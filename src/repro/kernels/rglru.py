@@ -33,12 +33,15 @@ def _kernel(a_ref, b_ref, h0_ref, h_ref, hout_ref, state_ref, *,
     def _init():
         state_ref[...] = h0_ref[0].astype(jnp.float32)   # (1, R_blk)
 
-    a = a_ref[0].astype(jnp.float32)                     # (T_blk, R_blk)
-    b = b_ref[0].astype(jnp.float32)
-
     def step(t, h):
-        h = a[t][None, :] * h + b[t][None, :]
-        h_ref[0, t, :] = h[0].astype(h_ref.dtype)
+        # Read one time row from the refs: indexing a loaded (T_blk, R_blk)
+        # value at a traced t lowers to dynamic_slice, which the TPU
+        # lowering does not implement; a dynamic ref window does lower.
+        row = pl.ds(t, 1)
+        a_t = a_ref[0, row, :].astype(jnp.float32)       # (1, R_blk)
+        b_t = b_ref[0, row, :].astype(jnp.float32)
+        h = a_t * h + b_t
+        h_ref[0, row, :] = h.astype(h_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, t_blk, step, state_ref[...])

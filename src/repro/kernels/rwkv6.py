@@ -27,7 +27,7 @@ _CLAMP = 40.0
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
-            s_ref, *, chunk: int, n_chunks: int):
+            s_ref, *, n_chunks: int):
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
@@ -38,32 +38,43 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
     kt = k_ref[0, 0].astype(jnp.float32)
     vt = v_ref[0, 0].astype(jnp.float32)
     wt = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)              # (N,)
+    u = u_ref[0].astype(jnp.float32)              # (1, N)
     s = s_ref[...]                                # (N, N)
-    L = chunk
+    L, N = rt.shape
 
     lw = jnp.log(jnp.clip(wt, 1e-38, None))       # ≤ 0
-    cum = jnp.cumsum(lw, axis=0)                  # lc_t   (L, N)
+    # Inclusive prefix sum over the chunk as a lower-triangular matmul:
+    # the TPU lowering has no cumsum.
+    tri = jnp.tril(jnp.ones((L, L), jnp.float32))
+    cum = jax.lax.dot_general(tri, lw, (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)  # lc_t
     cum_ex = cum - lw                             # lc_{t-1}
 
     # Pairwise decay D[t, s] = exp(lc_{t-1} − lc_s), strictly causal.
     diff = cum_ex[:, None, :] - cum[None, :, :]   # (L, L, N)
     decay = jnp.exp(jnp.clip(diff, -_CLAMP, 0.0))
-    scores = jnp.einsum("ln,mn,lmn->lm", rt, kt, decay)
+    scores = jnp.sum(rt[:, None, :] * kt[None, :, :] * decay, axis=-1)
     mask = jnp.tril(jnp.ones((L, L), jnp.float32), k=-1)
     scores = scores * mask
-    bonus = jnp.sum(rt * (u[None, :] * kt), axis=-1)          # (L,)
+    bonus = jnp.sum(rt * u * kt, axis=-1, keepdims=True)      # (L, 1)
     y = jax.lax.dot_general(scores, vt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    y = y + bonus[:, None] * vt
+    y = y + bonus * vt
     r_dec = rt * jnp.exp(jnp.clip(cum_ex, -_CLAMP, 0.0))
     y = y + jax.lax.dot_general(r_dec, s, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
-    tail = cum[-1:, :]                            # lc_L   (1, N)
+    tail = jnp.sum(lw, axis=0, keepdims=True)     # lc_L   (1, N)
     k_tail = kt * jnp.exp(jnp.clip(tail - cum, -_CLAMP, 0.0))
-    s_new = jnp.exp(jnp.clip(tail[0, :, None], -_CLAMP, 0.0)) * s \
+    # The state decays row-wise by exp(lc_L[i]).  lw^T @ 1 gives lc_L
+    # down the rows, [i, j] = lc_L[i], without a lane-to-sublane relayout.
+    tail_rows = jax.lax.dot_general(
+        lw, jnp.ones((L, N), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)       # (N, N)
+    s_new = jnp.exp(jnp.clip(tail_rows, -_CLAMP, 0.0)) * s \
         + jax.lax.dot_general(k_tail, vt, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
     s_ref[...] = s_new
@@ -88,7 +99,7 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         s0 = jnp.zeros((B, H, N, N), jnp.float32)
 
     grid = (B, H, n_chunks)
-    kernel = functools.partial(_kernel, chunk=chunk, n_chunks=n_chunks)
+    kernel = functools.partial(_kernel, n_chunks=n_chunks)
     seq_spec = pl.BlockSpec((1, 1, chunk, N),
                             lambda b, h, ic: (b, h, ic, 0))
     y, s_fin = pl.pallas_call(
@@ -96,7 +107,10 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         grid=grid,
         in_specs=[
             seq_spec, seq_spec, seq_spec, seq_spec,
-            pl.BlockSpec((1, N), lambda b, h, ic: (h, 0)),
+            # u goes in as (H, 1, N): a (1, N) block of an (H, N) array
+            # breaks the TPU rule that the last two block dims tile by
+            # (8, 128) or equal the array's.
+            pl.BlockSpec((1, 1, N), lambda b, h, ic: (h, 0, 0)),
             pl.BlockSpec((1, 1, N, N), lambda b, h, ic: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -109,5 +123,5 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, s0)
+    )(r, k, v, w, u.reshape(H, 1, N), s0)
     return y, s_fin

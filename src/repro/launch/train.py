@@ -15,6 +15,7 @@ import argparse
 from ..configs import get_config, get_smoke_config
 from ..train.trainer import Trainer, TrainerConfig
 from ..train.steps import StepConfig
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--compress", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke \
         else get_config(args.arch)

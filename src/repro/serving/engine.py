@@ -171,7 +171,22 @@ class ServingEngine:
             type_name=type_name, cost=cost, elapsed=elapsed,
             data=data or {}))
 
+    def _prefill_len(self, prompt_len: int) -> int:
+        """Positions a prompt is prefilled at: its power-of-two bucket
+        (at least 16) where bucketing applies, else its exact length."""
+        if self._bucketing:
+            return max(16, 1 << (prompt_len - 1).bit_length())
+        return prompt_len
+
     def submit(self, req: Request) -> Request:
+        n, padded = len(req.prompt), self._prefill_len(len(req.prompt))
+        if n >= self.max_len or padded > self.max_len:
+            # prefill would write past the cache (its ring branch keeps
+            # the last max_len positions, mostly padding), or the first
+            # decode would wrap onto position 0.
+            raise ValueError(
+                f"prompt of {n} tokens (prefilled at {padded} positions) "
+                f"does not fit the engine's max_len={self.max_len}")
         if req.request_id is None:
             req.request_id = next(self._ids)
         req.submitted_at = self._clock()
@@ -246,10 +261,8 @@ class ServingEngine:
             self._publish(EventKind.TASK_EXECUTE, req.request_id,
                           req.type_name, req.cost)
             t0 = self._clock()
-            toks = req.prompt
-            if self._bucketing:
-                bucket = max(16, 1 << (len(toks) - 1).bit_length())
-                toks = toks + [0] * (bucket - len(toks))
+            n = len(req.prompt)
+            toks = req.prompt + [0] * (self._prefill_len(n) - n)
             prompt = jnp.asarray([toks], jnp.int32)
             logits, cache1 = self._prefill(self.params, prompt)
             if self._bucketing:
@@ -258,6 +271,7 @@ class ServingEngine:
             self.cache = _scatter_cache(self.cache, cache1, slot)
             self.active[slot] = req
             req.output.append(first)
+            self.tokens_out += 1
             self.tokens = self.tokens.at[slot].set(first)
             self.pos = self.pos.at[slot].set(len(req.prompt))
             self.remaining[slot] = req.max_new_tokens - 1

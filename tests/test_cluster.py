@@ -6,7 +6,7 @@ multi-node sim→sim replay round trip."""
 import itertools
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import repro.runtime.task as task_mod
 from repro.core import (EventBus, GovernorSpec, ResourceBroker,

@@ -8,10 +8,9 @@ Covers the conditions subsystem end to end:
 * the ``PowerModel.power`` / ``MachineModel.service_time`` frequency
   clamp contracts (documented in their docstrings);
 * ``EnergyMeter`` lazy power-cap violation accounting;
-* ``ResourceBroker`` fail/recover invariants, deterministically and —
-  when hypothesis is installed — under random interleavings of the
-  sharing verbs (no core simultaneously lent and failed; pool counts
-  conserve);
+* ``ResourceBroker`` fail/recover invariants, deterministically and
+  under random interleavings of the sharing verbs (no core
+  simultaneously lent and failed; pool counts conserve);
 * perturbed sim→sim trace replays: the PERTURBATION events round-trip
   the timeline byte-exactly and replay-of-replay is a fixed point for
   every policy on both a homogeneous and a heterogeneous machine;
@@ -24,7 +23,7 @@ import json
 import random
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import EventBus, EventKind, GovernorSpec
 from repro.core.conditions import (ConditionTimeline, MachineConditions,

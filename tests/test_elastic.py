@@ -1,7 +1,7 @@
 """Elastic controller + straggler mitigation + fault-tolerant training."""
 
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import CheckpointManager
 from repro.core.conditions import (ConditionTimeline, core_fail,

@@ -3,7 +3,7 @@ accuracy, parent–child subtraction."""
 
 import math
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.monitoring import EMA, TaskMonitor
 

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          clip_by_global_norm, cosine_warmup, global_norm)
@@ -46,7 +46,10 @@ def test_cosine_warmup_shape():
     assert all(a >= b for a, b in zip(vals, vals[1:]))
 
 
-@given(st.lists(st.floats(-100, 100), min_size=1, max_size=32),
+# float32 values without subnormals: XLA flushes those to zero in any
+# arithmetic, so "unchanged under the cap" cannot hold for them.
+@given(st.lists(st.floats(-100, 100, width=32, allow_subnormal=False),
+                min_size=1, max_size=32),
        st.floats(0.1, 10.0))
 @settings(max_examples=100, deadline=None)
 def test_clip_property(vals, max_norm):

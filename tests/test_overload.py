@@ -10,7 +10,7 @@ import json
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.conditions import (ConditionTimeline, core_fail,
                                    core_recover, power_cap, straggler,
                                    thermal_throttle)

@@ -1,6 +1,6 @@
 """Policies (busy/idle/hybrid/prediction) + Algorithm 2 mechanics."""
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.manager import WorkerManager
 from repro.core.monitoring import TaskMonitor

@@ -1,6 +1,6 @@
 """Algorithm 1 — Δ prediction."""
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.monitoring import TaskMonitor
 from repro.core.prediction import CPUPredictor, PredictionConfig

@@ -110,10 +110,7 @@ _COLLECTIVE_PROBE = textwrap.dedent("""
                              sharding=NamedSharding(mesh, P("d", None)))
 
     def fn(x):
-        # one full all-reduce of a (1024,) f32 vector over 8 devices;
-        # the explicit NamedSharding constraint works with and without
-        # a jax.set_mesh context (jax.sharding.AxisType / jax.set_mesh
-        # do not exist on every supported jax version)
+        # one full all-reduce of a (1024,) f32 vector over 8 devices
         return jax.lax.with_sharding_constraint(
             x.sum(axis=0, keepdims=True),
             NamedSharding(mesh, P(None, None)))

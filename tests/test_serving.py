@@ -53,6 +53,24 @@ def test_slots_freed_and_reused():
     assert times == sorted(times)
 
 
+@pytest.mark.parametrize("n,fits", [(20, True), (32, False), (40, False)])
+def test_submit_rejects_prompt_that_does_not_fit(n, fits):
+    """A prompt fits when its prefill bucket is within max_len and its
+    first decode position is inside the cache; one that does not is
+    refused at submit instead of being truncated by the ring branch of
+    prefill (40 tokens bucket to 64, which is 2 × max_len)."""
+    engine = ServingEngine(CFG, PARAMS, max_batch=1, max_len=32)
+    req = Request(prompt=list(range(1, n + 1)), max_new_tokens=2)
+    if fits:
+        engine.submit(req)
+        engine.run_until_drained()
+        assert req.done and len(req.output) == 2
+        return
+    with pytest.raises(ValueError, match="max_len=32"):
+        engine.submit(req)
+    assert not engine.queue and req.request_id is None
+
+
 def test_autoscaler_tracks_load():
     monitor_engine = ServingEngine(CFG, PARAMS, max_batch=4, max_len=64)
     scaler = AutoScaler(monitor_engine.monitor, max_replicas=4,

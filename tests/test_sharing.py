@@ -1,6 +1,6 @@
 """DLB broker + sharing policies (paper §3.3, Table 3)."""
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.monitoring import TaskMonitor
 from repro.core.prediction import CPUPredictor, PredictionConfig
@@ -174,8 +174,8 @@ class TestBrokerInvariants:
             _check_invariants(b)
 
     def test_deterministic_interleaving(self):
-        """A fixed dense sequence so the invariants run even without
-        hypothesis installed."""
+        """A fixed dense sequence: the invariants on one known
+        interleaving."""
         b = _broker2()
         seq = [("lend_a", 0), ("lend_a", 1), ("acq_b", 0), ("reclaim_a", 0),
                ("ret_b", 0), ("lend_b", 1), ("lend_b", 4), ("acq_a", 2),
@@ -221,7 +221,7 @@ class TestBrokerInvariantsNJobs:
             _check_invariants(b)
 
     def test_deterministic_interleaving_5_jobs(self):
-        """Dense 5-job sequence; runs even without hypothesis."""
+        """Dense 5-job sequence."""
         b = _broker_n(5)
         seq = [("lend", "j0", 0), ("lend", "j0", 1), ("lend", "j3", 6),
                ("acq", "j1", 2), ("acq", "j2", 1), ("reclaim", "j0", 0),
