@@ -79,6 +79,13 @@ class EventKind(enum.Enum):
     #: a power cap, or a circuit breaker quarantining / re-probing a
     #: replica (``data["mode"]``)
     DEGRADE = "degrade"
+    #: one phase of a producer's own work, published when it ends
+    #: (``type_name`` = span name, ``elapsed`` = its length, ``task_id`` =
+    #: the request that caused it, if one did; ``data``: the span's
+    #: arguments and ``parent``, the enclosing span's name).  The
+    #: ``ServingEngine`` writes the same spans to the ``jax.profiler``
+    #: trace, on the device's clock
+    SPAN = "span"
 
 
 @dataclass(frozen=True, slots=True)

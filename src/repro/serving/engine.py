@@ -7,14 +7,26 @@ into a free slot; finished slots free immediately — no head-of-line
 blocking on long generations.
 
 The engine feeds the paper's monitoring infrastructure: every request is
-a *task* with a cost clause (prompt_len + max_new_tokens), prefill and
-decode timings are aggregated per type, and the
+a *task* with a cost clause (prompt_len + max_new_tokens), prefill
+timings are aggregated per type, and the
 :class:`~repro.serving.autoscale.AutoScaler` turns Algorithm 1 into a
 replica/slot target Δ.
+
+Its phases are spans (``_Span``): each is a ``jax.profiler``
+annotation, on the device trace's clock, and, where the bus has a
+``SPAN`` subscriber, a ``SPAN`` event.  An admission is ``engine.admit``
+(arguments ``req``, ``queue_ms``, ``prompt``, ``bucket``) around
+``engine.prefill``, ``engine.first_token`` and ``engine.scatter``; a
+decode step is ``engine.decode`` (``live``) around
+``engine.decode.dispatch``, ``engine.decode.readback`` and
+``engine.decode.finish``.  Each of the two closes with a zero-length
+``engine.counts`` child whose ``syncs`` counts the device→host reads it
+made.
 """
 
 from __future__ import annotations
 
+import functools
 import itertools
 import time
 from dataclasses import dataclass, field
@@ -48,6 +60,8 @@ class Request:
     # -- filled by the engine ------------------------------------------
     output: list[int] = field(default_factory=list)
     submitted_at: float = 0.0
+    #: when the engine took it off the queue to prefill it
+    admitted_at: float | None = None
     done_at: float | None = None
 
     @property
@@ -86,6 +100,53 @@ def _scatter_cache(dst: dict, src: dict, slot: int) -> dict:
     }
 
 
+def _named(fn: functools.partial) -> functools.partial:
+    """``fn`` under its function's name, so that ``jax.jit`` names the
+    program after it (``jit_decode_step``; a bare partial compiles as
+    ``jit__unknown``) and the device trace can tell the programs apart."""
+    fn.__name__ = fn.func.__name__
+    return fn
+
+
+class _Span:
+    """One phase of engine work, written to two sinks: a
+    ``jax.profiler.TraceAnnotation`` (its arguments become the event's
+    stats), and, only where some subscriber wants ``SPAN`` events, a
+    ``RuntimeEvent`` on the engine's bus and clock (``time`` its end,
+    ``elapsed`` its length, ``data`` its arguments and its parent's
+    name)."""
+
+    __slots__ = ("engine", "name", "task_id", "args", "ann", "t0",
+                 "parent")
+
+    def __init__(self, engine: "ServingEngine", name: str,
+                 task_id: int | None = None, **args) -> None:
+        self.engine, self.name, self.task_id = engine, name, task_id
+        self.args = args
+        self.ann = jax.profiler.TraceAnnotation(name, **args)
+        self.t0: float | None = None
+
+    def __enter__(self) -> "_Span":
+        self.ann.__enter__()
+        eng = self.engine
+        if eng.bus.interested(EventKind.SPAN):
+            self.parent = eng._open_spans[-1] if eng._open_spans else None
+            eng._open_spans.append(self.name)
+            self.t0 = eng._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.t0 is not None:
+            eng = self.engine
+            end = eng._clock()
+            eng._open_spans.pop()
+            eng.bus.publish(RuntimeEvent(
+                kind=EventKind.SPAN, time=end, task_id=self.task_id,
+                type_name=self.name, elapsed=end - self.t0,
+                data={**self.args, "parent": self.parent}))
+        self.ann.__exit__(*exc)
+
+
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 4,
                  max_len: int = 256, monitor: TaskMonitor | None = None,
@@ -108,9 +169,9 @@ class ServingEngine:
         self.brownout_tokens = brownout_tokens
         #: requests refused by admission control (terminal; not queued)
         self.shed: list[Request] = []
-        # Per-engine id stream for requests and decode ticks (was a
-        # module global, which interleaved ids across engines and made
-        # single-engine traces depend on process history).
+        # Per-engine id stream for requests (was a module global, which
+        # interleaved ids across engines and made single-engine traces
+        # depend on process history).
         self._ids = itertools.count()
         # The engine is the workload side of the paper's loop: it
         # publishes request lifecycle events on ``self.bus``; the monitor
@@ -146,8 +207,8 @@ class ServingEngine:
         self.tokens = jnp.zeros((max_batch,), jnp.int32)
         self.pos = jnp.zeros((max_batch,), jnp.int32)
         self.remaining = np.zeros((max_batch,), np.int64)
-        self._decode = jax.jit(
-            lambda p, t, pos, c: decode_step(p, t, pos, c, cfg))
+        self._decode = jax.jit(_named(functools.partial(decode_step,
+                                                        cfg=cfg)))
         # Prompt-length bucketing avoids a recompile per length.  Right-
         # padding is safe for attention archs (pad slots sit after `pos`
         # and are causally invisible); recurrent states would absorb the
@@ -155,11 +216,16 @@ class ServingEngine:
         from ..models.config import LayerKind
         self._bucketing = all(k in (LayerKind.ATTN, LayerKind.MOE)
                               for k in cfg.pattern)
-        self._prefill = jax.jit(
-            lambda p, t: prefill(p, t, cfg, max_len=max_len,
-                                 return_all_logits=self._bucketing))
+        self._prefill = jax.jit(_named(functools.partial(
+            prefill, cfg=cfg, max_len=max_len,
+            return_all_logits=self._bucketing)))
         self.ticks = 0
         self.tokens_out = 0
+        #: device→host reads so far: an admission's first token, a
+        #: tick's tokens, and a live slot's position
+        self.host_syncs = 0
+        # names of the spans open on the bus, innermost last
+        self._open_spans: list[str] = []
 
     # -- request lifecycle ---------------------------------------------------
 
@@ -170,6 +236,13 @@ class ServingEngine:
             kind=kind, time=self._clock(), task_id=task_id,
             type_name=type_name, cost=cost, elapsed=elapsed,
             data=data or {}))
+
+    def _counts(self, task_id: int | None, syncs_before: int) -> None:
+        """The zero-length last child of an admission or decode span,
+        carrying the device→host reads made since ``syncs_before``."""
+        with _Span(self, "engine.counts", task_id,
+                   syncs=self.host_syncs - syncs_before):
+            pass
 
     def _prefill_len(self, prompt_len: int) -> int:
         """Positions a prompt is prefilled at: its power-of-two bucket
@@ -260,24 +333,34 @@ class ServingEngine:
             req = self._pop_next()
             self._publish(EventKind.TASK_EXECUTE, req.request_id,
                           req.type_name, req.cost)
-            t0 = self._clock()
+            t0 = req.admitted_at = self._clock()
             n = len(req.prompt)
-            toks = req.prompt + [0] * (self._prefill_len(n) - n)
-            prompt = jnp.asarray([toks], jnp.int32)
-            logits, cache1 = self._prefill(self.params, prompt)
-            if self._bucketing:
-                logits = logits[:, len(req.prompt) - 1]
-            first = int(jnp.argmax(logits[0, :self.cfg.vocab]))
-            self.cache = _scatter_cache(self.cache, cache1, slot)
-            self.active[slot] = req
-            req.output.append(first)
-            self.tokens_out += 1
-            self.tokens = self.tokens.at[slot].set(first)
-            self.pos = self.pos.at[slot].set(len(req.prompt))
-            self.remaining[slot] = req.max_new_tokens - 1
+            bucket = self._prefill_len(n)
+            rid, syncs = req.request_id, self.host_syncs
+            with _Span(self, "engine.admit", rid, req=rid,
+                       queue_ms=(t0 - req.submitted_at) * 1e3, prompt=n,
+                       bucket=bucket):
+                with _Span(self, "engine.prefill", rid):
+                    prompt = jnp.asarray([req.prompt + [0] * (bucket - n)],
+                                         jnp.int32)
+                    logits, cache1 = self._prefill(self.params, prompt)
+                    if self._bucketing:
+                        logits = logits[:, n - 1]
+                with _Span(self, "engine.first_token", rid):
+                    self.host_syncs += 1
+                    first = int(jnp.argmax(logits[0, :self.cfg.vocab]))
+                with _Span(self, "engine.scatter", rid):
+                    self.cache = _scatter_cache(self.cache, cache1, slot)
+                    self.tokens = self.tokens.at[slot].set(first)
+                    self.pos = self.pos.at[slot].set(n)
+                self.active[slot] = req
+                req.output.append(first)
+                self.tokens_out += 1
+                self.remaining[slot] = req.max_new_tokens - 1
+                self._counts(rid, syncs)
             elapsed = self._clock() - t0
-            self._publish(EventKind.TASK_COMPLETED, req.request_id * 2 + 1,
-                          "prefill", float(len(req.prompt)), elapsed)
+            self._publish(EventKind.TASK_COMPLETED, rid * 2 + 1,
+                          "prefill", float(n), elapsed)
 
     # -- decode tick ------------------------------------------------------------
 
@@ -287,33 +370,40 @@ class ServingEngine:
         live = [s for s, r in enumerate(self.active) if r is not None]
         if not live:
             return 0
-        t0 = self._clock()
-        logits, self.cache = self._decode(self.params, self.tokens,
-                                          self.pos, self.cache)
-        nxt = jnp.argmax(logits[:, :self.cfg.vocab], axis=-1) \
-            .astype(jnp.int32)
-        self.tokens = nxt
-        self.pos = self.pos + 1
-        elapsed = self._clock() - t0
-        self._publish(EventKind.TASK_COMPLETED, next(self._ids) * 2,
-                      "decode_tick", float(len(live)), elapsed)
-        self.ticks += 1
-        nxt_host = np.asarray(nxt)
-        for s in live:
-            req = self.active[s]
-            assert req is not None
-            tok = int(nxt_host[s])
-            req.output.append(tok)
-            self.tokens_out += 1
-            self.remaining[s] -= 1
-            hit_eos = req.eos_id is not None and tok == req.eos_id
-            if self.remaining[s] <= 0 or hit_eos \
-                    or int(self.pos[s]) >= self.max_len - 1:
-                req.done_at = self._clock()
-                self._publish(EventKind.TASK_COMPLETED, req.request_id,
-                              req.type_name, req.cost,
-                              req.done_at - req.submitted_at)
-                self.active[s] = None
+        syncs = self.host_syncs
+        with _Span(self, "engine.decode", live=len(live)):
+            with _Span(self, "engine.decode.dispatch"):
+                logits, self.cache = self._decode(self.params, self.tokens,
+                                                  self.pos, self.cache)
+                nxt = jnp.argmax(logits[:, :self.cfg.vocab], axis=-1) \
+                    .astype(jnp.int32)
+                self.tokens = nxt
+                self.pos = self.pos + 1
+            self.ticks += 1
+            with _Span(self, "engine.decode.readback"):
+                self.host_syncs += 1
+                nxt_host = np.asarray(nxt)
+            with _Span(self, "engine.decode.finish"):
+                for s in live:
+                    req = self.active[s]
+                    assert req is not None
+                    tok = int(nxt_host[s])
+                    req.output.append(tok)
+                    self.tokens_out += 1
+                    self.remaining[s] -= 1
+                    done = self.remaining[s] <= 0 or (
+                        req.eos_id is not None and tok == req.eos_id)
+                    if not done:
+                        self.host_syncs += 1
+                        done = int(self.pos[s]) >= self.max_len - 1
+                    if done:
+                        req.done_at = self._clock()
+                        self._publish(EventKind.TASK_COMPLETED,
+                                      req.request_id, req.type_name,
+                                      req.cost,
+                                      req.done_at - req.submitted_at)
+                        self.active[s] = None
+            self._counts(None, syncs)
         return len(live)
 
     def run_until_drained(self, max_ticks: int = 100_000) -> None:

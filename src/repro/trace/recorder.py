@@ -126,12 +126,20 @@ class TraceRecorder:
 
         Tasks become complete (``ph="X"``) slices on per-worker lanes
         (EXECUTE→COMPLETED pairs; COMPLETED-only events — e.g. serving
-        prefill/decode ticks — are reconstructed from their elapsed), and
-        every PREDICTION tick becomes a Δ counter sample.
+        prefills — are reconstructed from their elapsed), every SPAN
+        event — e.g. the serving engine's ``engine.admit``,
+        ``engine.decode`` and their children — becomes an ``X`` slice on
+        lane 0 named for the span, with its arguments, and every
+        PREDICTION tick becomes a Δ counter sample.  These times are the
+        engine's clock; the same spans on the device's clock are in a
+        ``jax.profiler`` trace of the run.
         """
         events = self.merged_events()
         if events:
-            t0 = min(ev.time for ev in events)
+            # a span is published at its end
+            t0 = min(ev.time - ev.elapsed if ev.kind is EventKind.SPAN
+                     and ev.elapsed is not None else ev.time
+                     for ev in events)
         else:
             t0 = 0.0
         us = 1e6
@@ -158,6 +166,13 @@ class TraceRecorder:
                     "ts": ts, "dur": max(dur, 0.0), "pid": 0,
                     "tid": tid if tid is not None else 0,
                     "args": {"task_id": ev.task_id, "cost": ev.cost},
+                })
+            elif ev.kind is EventKind.SPAN and ev.elapsed is not None:
+                out.append({
+                    "name": ev.type_name or "span", "ph": "X",
+                    "ts": (ev.time - ev.elapsed - t0) * us,
+                    "dur": max(ev.elapsed, 0.0) * us, "pid": 0, "tid": 0,
+                    "args": {"task_id": ev.task_id, **ev.data},
                 })
             elif ev.kind is EventKind.PREDICTION:
                 out.append({

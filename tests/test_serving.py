@@ -116,3 +116,72 @@ def test_autoscaler_never_exceeds_max_replicas_when_oversubscribed():
         monitor.on_task_ready(100 + i, "req", 1.0)
     assert scaler.predictor.compute_delta() > 4   # Δ oversubscribes...
     assert scaler.target(12, 0) == 4              # ...the target cannot
+
+
+def _served(bus=None, n=2):
+    engine = ServingEngine(CFG, PARAMS, max_batch=1, max_len=64, bus=bus)
+    reqs = [engine.submit(Request(prompt=[i + 1, 2, 3], max_new_tokens=3))
+            for i in range(n)]
+    engine.run_until_drained()
+    return engine, reqs
+
+
+def test_engine_spans_reach_a_span_subscriber():
+    from repro.core import EventBus, EventKind
+
+    bus = EventBus()
+    got = []
+    bus.subscribe(got.append, kinds=[EventKind.SPAN])
+    engine, reqs = _served(bus)
+    by = {}
+    for ev in got:
+        by.setdefault(ev.type_name, []).append(ev)
+    assert set(by) == {"engine.admit", "engine.prefill", "engine.first_token",
+                       "engine.scatter", "engine.decode",
+                       "engine.decode.dispatch", "engine.decode.readback",
+                       "engine.decode.finish", "engine.counts"}
+    assert [ev.task_id for ev in by["engine.admit"]] == \
+        [r.request_id for r in reqs]
+    for ev, r in zip(by["engine.admit"], reqs):
+        assert ev.data["queue_ms"] == (r.admitted_at - r.submitted_at) * 1e3
+        assert ev.data["parent"] is None and ev.elapsed >= 0
+    assert {ev.data["parent"] for ev in by["engine.prefill"]} == \
+        {"engine.admit"}
+    assert {ev.data["parent"] for ev in by["engine.decode.readback"]} == \
+        {"engine.decode"}
+    assert all(ev.task_id is None for ev in by["engine.decode"])
+    # a span ends before its parent does, and counts close each parent
+    assert [ev.data["parent"] for ev in by["engine.counts"]] == \
+        ["engine.admit", "engine.decode", "engine.decode"] * 2
+    assert sum(ev.data["syncs"] for ev in by["engine.counts"]) == \
+        engine.host_syncs == 2 * (1 + 2 + 1)
+
+
+def test_engine_builds_no_span_event_without_a_span_subscriber(monkeypatch):
+    import repro.serving.engine as engine_mod
+    from repro.core import EventBus, EventKind
+
+    built, event = [], engine_mod.RuntimeEvent
+
+    def counting(*args, **kw):
+        built.append(kw["kind"])
+        return event(*args, **kw)
+
+    monkeypatch.setattr(engine_mod, "RuntimeEvent", counting)
+    bus = EventBus()
+    done = []
+    bus.subscribe(done.append, kinds=[EventKind.TASK_COMPLETED])
+    engine, reqs = _served(bus)
+    assert all(r.done for r in reqs) and done
+    assert EventKind.SPAN not in built and built
+
+
+def test_monitor_sees_requests_and_prefills_only():
+    """Ticks publish no task events: the monitor holds no decode-tick
+    type, and request ids run on without gaps between ticks."""
+    engine, reqs = _served()
+    assert sorted(engine.monitor.type_names()) == ["prefill", "request"]
+    assert [r.request_id for r in reqs] == [0, 1]
+    late = engine.submit(Request(prompt=[4, 5], max_new_tokens=2))
+    assert late.request_id == 2 and engine.ticks > 0
+    assert all(r.admitted_at >= r.submitted_at for r in reqs)

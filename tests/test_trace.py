@@ -255,3 +255,27 @@ def test_unreplayable_trace_rejected():
                              data={"deps": []}))
     with pytest.raises(ValueError, match="never completed"):
         TraceReplayer(rec).build()
+
+
+def test_chrome_export_renders_spans(tmp_path):
+    from repro.core import RuntimeEvent
+
+    bus = EventBus()
+    rec = TraceRecorder(bus=bus)
+    bus.publish(RuntimeEvent(kind=EventKind.SPAN, time=2.0, task_id=3,
+                             type_name="engine.admit", elapsed=1.5,
+                             data={"queue_ms": 4.0, "parent": None}))
+    bus.publish(RuntimeEvent(kind=EventKind.SPAN, time=1.0, task_id=3,
+                             type_name="engine.prefill", elapsed=0.25,
+                             data={"parent": "engine.admit"}))
+    doc = json.loads(rec.to_chrome(tmp_path / "t.json").read_text())
+    admit, prefill = doc["traceEvents"]
+    # the trace opens where the first span began
+    assert admit == {"name": "engine.admit", "ph": "X", "ts": 0.0,
+                     "dur": 1.5e6, "pid": 0, "tid": 0,
+                     "args": {"task_id": 3, "queue_ms": 4.0,
+                              "parent": None}}
+    assert prefill["name"] == "engine.prefill" and prefill["ph"] == "X"
+    assert prefill["ts"] == pytest.approx(0.25e6)
+    assert prefill["dur"] == pytest.approx(0.25e6)
+    assert prefill["args"]["parent"] == "engine.admit"
