@@ -22,6 +22,11 @@ decode step is ``engine.decode`` (``live``) around
 ``engine.decode.finish``.  Each of the two closes with a zero-length
 ``engine.counts`` child whose ``syncs`` counts the device→host reads it
 made.
+
+Slot positions and token budgets live on the host (``pos``,
+``remaining``); the device gets a copy of the positions with each step.
+So a decode step reads the device once, for its tokens, however many
+slots are live, and an admission once, for its first token.
 """
 
 from __future__ import annotations
@@ -205,7 +210,9 @@ class ServingEngine:
         self.active: list[Request | None] = [None] * max_batch
         self.cache = init_cache(cfg, max_batch, max_len)
         self.tokens = jnp.zeros((max_batch,), jnp.int32)
-        self.pos = jnp.zeros((max_batch,), jnp.int32)
+        # the only copy of the slot positions; each step gets its own
+        # snapshot, so this one can change in place
+        self.pos = np.zeros((max_batch,), np.int32)
         self.remaining = np.zeros((max_batch,), np.int64)
         self._decode = jax.jit(_named(functools.partial(decode_step,
                                                         cfg=cfg)))
@@ -221,8 +228,8 @@ class ServingEngine:
             return_all_logits=self._bucketing)))
         self.ticks = 0
         self.tokens_out = 0
-        #: device→host reads so far: an admission's first token, a
-        #: tick's tokens, and a live slot's position
+        #: device→host reads so far: an admission's first token and a
+        #: tick's tokens (positions are on the host)
         self.host_syncs = 0
         # names of the spans open on the bus, innermost last
         self._open_spans: list[str] = []
@@ -352,7 +359,7 @@ class ServingEngine:
                 with _Span(self, "engine.scatter", rid):
                     self.cache = _scatter_cache(self.cache, cache1, slot)
                     self.tokens = self.tokens.at[slot].set(first)
-                    self.pos = self.pos.at[slot].set(n)
+                    self.pos[slot] = n
                 self.active[slot] = req
                 req.output.append(first)
                 self.tokens_out += 1
@@ -374,11 +381,11 @@ class ServingEngine:
         with _Span(self, "engine.decode", live=len(live)):
             with _Span(self, "engine.decode.dispatch"):
                 logits, self.cache = self._decode(self.params, self.tokens,
-                                                  self.pos, self.cache)
+                                                  self.pos.copy(), self.cache)
                 nxt = jnp.argmax(logits[:, :self.cfg.vocab], axis=-1) \
                     .astype(jnp.int32)
                 self.tokens = nxt
-                self.pos = self.pos + 1
+                self.pos += 1
             self.ticks += 1
             with _Span(self, "engine.decode.readback"):
                 self.host_syncs += 1
@@ -391,11 +398,10 @@ class ServingEngine:
                     req.output.append(tok)
                     self.tokens_out += 1
                     self.remaining[s] -= 1
-                    done = self.remaining[s] <= 0 or (
-                        req.eos_id is not None and tok == req.eos_id)
-                    if not done:
-                        self.host_syncs += 1
-                        done = int(self.pos[s]) >= self.max_len - 1
+                    done = (self.remaining[s] <= 0
+                            or self.pos[s] >= self.max_len - 1
+                            or (req.eos_id is not None
+                                and tok == req.eos_id))
                     if done:
                         req.done_at = self._clock()
                         self._publish(EventKind.TASK_COMPLETED,

@@ -2,6 +2,7 @@
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
@@ -150,11 +151,12 @@ def test_engine_spans_reach_a_span_subscriber():
     assert {ev.data["parent"] for ev in by["engine.decode.readback"]} == \
         {"engine.decode"}
     assert all(ev.task_id is None for ev in by["engine.decode"])
-    # a span ends before its parent does, and counts close each parent
+    # a span ends before its parent does, and counts close each parent;
+    # an admission reads its first token, a decode step its tokens
     assert [ev.data["parent"] for ev in by["engine.counts"]] == \
         ["engine.admit", "engine.decode", "engine.decode"] * 2
     assert sum(ev.data["syncs"] for ev in by["engine.counts"]) == \
-        engine.host_syncs == 2 * (1 + 2 + 1)
+        engine.host_syncs == 2 * (1 + 1 + 1)
 
 
 def test_engine_builds_no_span_event_without_a_span_subscriber(monkeypatch):
@@ -185,3 +187,67 @@ def test_monitor_sees_requests_and_prefills_only():
     late = engine.submit(Request(prompt=[4, 5], max_new_tokens=2))
     assert late.request_id == 2 and engine.ticks > 0
     assert all(r.admitted_at >= r.submitted_at for r in reqs)
+
+
+@pytest.mark.parametrize("live", [1, 2, 3, 4])
+def test_a_decode_tick_reads_the_device_once(live):
+    """However many slots are live, a decode step makes one device→host
+    read, its tokens: ``syncs`` of every ``engine.decode`` is 1, and
+    ``host_syncs`` grows by one a tick past the admissions' first
+    tokens."""
+    from repro.core import EventBus, EventKind
+
+    bus = EventBus()
+    got = []
+    bus.subscribe(got.append, kinds=[EventKind.SPAN])
+    engine = ServingEngine(CFG, PARAMS, max_batch=4, max_len=64, bus=bus)
+    for i in range(live):
+        engine.submit(Request(prompt=[i + 1, 2, 3], max_new_tokens=5))
+    engine.tick()                          # admits every request
+    assert engine.host_syncs == live + 1
+    for _ in range(3):
+        before = engine.host_syncs
+        assert engine.tick() == live
+        assert engine.host_syncs == before + 1
+    decode = [ev for ev in got if ev.type_name == "engine.counts"
+              and ev.data["parent"] == "engine.decode"]
+    assert [ev.data["syncs"] for ev in decode] == [1] * 4
+
+
+def test_a_request_past_the_cache_end_stops_at_the_last_position():
+    """A budget longer than the cache allows ends where the positions
+    do, on the host's count, with the greedy reference's tokens."""
+    prompt = list(range(3, 23))
+    engine = ServingEngine(CFG, PARAMS, max_batch=2, max_len=32)
+    req = engine.submit(Request(prompt=prompt, max_new_tokens=100))
+    engine.run_until_drained()
+    assert req.done and len(req.output) == 32 - len(prompt)
+    assert engine.ticks == len(req.output) - 1
+    assert req.output == _greedy_reference(prompt, len(req.output))
+
+
+def test_host_positions_follow_prompt_and_ticks():
+    """Each live slot's host position is its prompt length plus the
+    ticks since its admission (the admitting tick included), and the
+    decode program sees one shape throughout."""
+    engine = ServingEngine(CFG, PARAMS, max_batch=3, max_len=64)
+    admitted = {}                          # request -> tick it entered
+
+    def submit(n):
+        req = engine.submit(Request(prompt=list(range(1, n + 1)),
+                                    max_new_tokens=50))
+        admitted[id(req)] = (req, engine.ticks + 1)
+
+    submit(3)
+    engine.tick()
+    engine.tick()
+    submit(7)
+    submit(18)
+    for _ in range(3):
+        engine.tick()
+        for slot, req in enumerate(engine.active):
+            r, t = admitted[id(req)]
+            assert engine.pos[slot] == len(r.prompt) + engine.ticks - t + 1
+    assert sum(r is not None for r in engine.active) == 3
+    assert engine.pos.dtype == np.int32 and engine.pos.shape == (3,)
+    assert engine._decode._cache_size() == 1
