@@ -3,8 +3,9 @@
 Once the window has closed and the program's state is freed, a sample of
 the requests due in the window that finished, drawn from the seed and
 always holding the longest one and the one with the longest prompt (the
-largest prefill bucket reached), is run through the float32 reference
-(``reference.py``): each prompt with the tokens served for it.  At every
+largest prefill bucket reached), is run through the configuration's
+float32 reference (``reference.py`` unless its file names another, see
+``spec``): each prompt with the tokens served for it.  At every
 served token the gap between the reference's best logit and the
 reference's logit of the token served is read.  Served tokens are greedy
 (the engine takes the argmax), so a sound program serves the reference's
@@ -32,7 +33,6 @@ from __future__ import annotations
 import numpy as np
 
 from .arrivals import seed_rng
-from .reference import Arch, logits_at
 
 __all__ = ["sample", "gaps", "checks"]
 
@@ -63,13 +63,16 @@ def _add(acc: dict, gap: np.ndarray) -> None:
     acc["flips"] += int((gap > 0).sum())
 
 
-def gaps(params, model: dict, chosen: list, control: bool = False) -> dict:
-    """Gaps below the reference's best logit of the served tokens
-    (``served``) and, with ``control``, of the tokens that the float8
-    reference puts first at the same positions (``control``).  Each has
-    the widest gap, their sum and the count of tokens that are not the
-    reference's best."""
-    a = Arch(model)
+def gaps(ref, params, model: dict, chosen: list, control: bool = False
+         ) -> dict:
+    """Gaps below the best logit of the reference ``ref`` (the
+    configuration's module: its ``Arch`` and ``logits_at`` judge) of the
+    served tokens (``served``) and, with ``control``, of the tokens that
+    the float8 reference puts first at the same positions (``control``).
+    Each has the widest gap, their sum and the count of tokens that are
+    not the reference's best."""
+    logits_at = ref.logits_at
+    a = ref.Arch(model)
     kinds = ("served", "control") if control else ("served",)
     out = {k: {"widest": 0.0, "sum": 0.0, "flips": 0} for k in kinds}
     out["tokens"], out["requests"] = 0, len(chosen)

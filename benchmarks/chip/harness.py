@@ -10,14 +10,16 @@ configuration file, plans the traffic from its mix file, and warms every
 program the window will run: one admission in each prefill bucket the
 plan reaches, and the decode step.  The window then drives the engine as
 an open or closed loop (``driver``).  After it the engine's state is
-freed and the float32 reference judges a sample of what was served
-(``correct``).
+freed and the configuration's float32 reference judges a sample of what
+was served (``correct``).
 
 The last line of standard output is one JSON object; the numbers
 compared, with their limits, close standard error and that object.
 ``--trace 1`` records a profiler trace of the whole window and drain, and
 reports the per-layer metrics over the window's first ``TRACE_S`` seconds
-instead of the end-to-end ones.
+instead of the end-to-end ones: the trace is parsed once, after the
+drain, for the device's busy time (``trace``) and the engine's spans and
+programs (``spans``).
 """
 
 from __future__ import annotations
@@ -38,7 +40,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.models import ModelConfig
 from repro.serving import AutoScaler, ServingEngine
 
-from . import arrivals, correct, trace as tr
+from . import arrivals, correct, spans, trace as tr
 from .driver import drain, run_closed, run_open
 from .record import Record
 from .spec import ROOT, Cell, load_benchmark, metric_reader
@@ -99,7 +101,8 @@ def plan_for(cell: Cell, seed: int, seconds: float, engine) -> tuple:
 def warm(engine, scaler, plan, annotate) -> None:
     """Serve one short request in each prefill bucket of ``plan``; this
     compiles the prefills, the admission's eager programs and the decode
-    step (4 tokens, so that the per-slot position read runs too)."""
+    step (4 tokens each: three decode steps, the last of which ends the
+    request)."""
     bs = sorted({arrivals.prefill_bucket(len(p.prompt)) for p in plan})
     warm_plan = [arrivals.Planned(0.0, [1] * (b - 1), 4) for b in bs]
     drain(engine, scaler, warm_plan, clock=time.perf_counter,
@@ -171,16 +174,24 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     jax.monitoring.unregister_event_duration_listener(on_event)
     stats = [d.memory_stats() or {} for d in devs]
     peak = max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
-    reduced = None
+    reduced = engine_trace = None
+    t_read = {}
     if trace:
         jax.profiler.stop_trace()
-        reduced = tr.reduce(*tr.read_xplane(tr.newest_xplane(log_dir)),
-                            seconds=stretch)
+        t0 = time.perf_counter()
+        profile = tr.load(tr.newest_xplane(log_dir))
+        t1 = time.perf_counter()
+        reduced = tr.reduce(*tr.read_xplane(profile), seconds=stretch)
+        t2 = time.perf_counter()
+        engine_trace = spans.read(profile, seconds=stretch)
+        t_read = {"load": t1 - t0, "reduce": t2 - t1,
+                  "spans": time.perf_counter() - t2}
+        del profile
         tmp.cleanup()
     rec = Record(run=run, model=cell.config["model"], setup_s=setup_s,
                  device_kind=devs[0].device_kind, seconds=stretch,
                  prefills=[(t - run.t0, e) for t, e in prefills],
-                 trace=reduced)
+                 trace=reduced, engine=engine_trace, counts=cell.counts)
     wanted = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in wanted:
@@ -195,7 +206,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     t_ref = time.perf_counter()
     limits = cell.config["correct"]
     chosen = correct.sample(run, seed, int(limits["sample_tokens"]))
-    g = correct.gaps(params, cell.config["model"], chosen, control=control)
+    g = correct.gaps(cell.reference, params, cell.config["model"], chosen,
+                     control=control)
     t_ref = time.perf_counter() - t_ref
     checks = correct.checks(run, g, limits,
                             "control" if control else "served")
@@ -211,11 +223,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if reduced is not None:
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
+        if engine_trace is not None:
+            result["breakdown"]["engine_idle"] = engine_trace.top_idle()
     result["info"] = {"compiles_in_window": compiles_in_window,
                       "sample_requests": g["requests"],
                       "sample_tokens": g["tokens"],
                       "drain_end_s": run.end,
                       "reference_s": t_ref,
+                      "trace_read_s": t_read,
                       "requests_served": sum(1 for s in run.served
                                              if s.stamps)}
     result["info"]["gaps"] = {k: g[k] for k in g

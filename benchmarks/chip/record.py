@@ -3,10 +3,13 @@
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from types import ModuleType
 
 import numpy as np
 
+from . import flops
 from .driver import Run
+from .spans import EngineTrace
 
 __all__ = ["Record", "pct", "mean"]
 
@@ -27,6 +30,11 @@ class Record:
     prefills: list = field(default_factory=list)
     #: ``trace.reduce`` of the traced run; None without a trace
     trace: dict | None = None
+    #: the engine's spans and programs over the same stretch
+    #: (``spans.read``); None without a trace
+    engine: EngineTrace | None = None
+    #: the configuration's counts module (``spec.Cell.counts``)
+    counts: ModuleType = flops
 
     @property
     def window(self) -> list:

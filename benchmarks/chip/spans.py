@@ -28,7 +28,6 @@ come in time order, and a traced run holds minutes more.
 
 from __future__ import annotations
 
-import gzip
 from collections import defaultdict
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -36,7 +35,7 @@ from pathlib import Path
 import numpy as np
 
 from .trace import MODULES_LINE, OPS_LINE, OUTSIDE, SPANS, TOP, \
-    _busy_before, _merge
+    _busy_before, _merge, load
 
 __all__ = ["PREFIX", "COUNTS", "EngineSpan", "EngineTrace", "read",
            "reduce"]
@@ -82,18 +81,10 @@ def _program(name: str) -> str:
     return name.split("(", 1)[0]
 
 
-def read(path: Path, seconds: float | None = None) -> EngineTrace | None:
-    """The engine's spans in the trace file at ``path`` (``.xplane.pb``,
-    or the same compressed, ``.xplane.pb.gz``); None where it holds no
-    driver span."""
-    from jax.profiler import ProfileData
-
-    path = Path(path)
-    if path.suffix == ".gz":
-        pd = ProfileData.from_serialized_xspace(
-            gzip.decompress(path.read_bytes()))
-    else:
-        pd = ProfileData.from_file(str(path))
+def read(src, seconds: float | None = None) -> EngineTrace | None:
+    """The engine's spans in one trace: a file's path, or the file as
+    ``trace.load`` parsed it; None where it holds no driver span."""
+    pd = load(src) if isinstance(src, (str, Path)) else src
     host = []
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):

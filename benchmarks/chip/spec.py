@@ -4,17 +4,33 @@
 cell's configuration is the file its ``configs`` entry names, its traffic
 mix is ``traffic/<traffic>.json``, and each metric, end-to-end or
 per-layer, is read by ``metrics/<name>.py``, whose ``read(record)``
-returns a number, or None where the run gave it nothing to read.  A later change adds a cell, a mix
-or a metric by adding files and entries, and edits no file here.
+returns a number, or None where the run gave it nothing to read.
+
+A configuration's file may name ``"reference"``, the module that judges
+its served tokens (``Arch`` and ``logits_at(params, tokens, rows, arch,
+quant=False)``, as ``reference.py`` has them), and ``"counts"``, the module
+that counts its work (``prefill_flops(model, n)``, ``decode_flops(model,
+keys)``, ``weight_bytes(model)`` and ``kv_bytes(model, keys)``, as
+``flops.py`` has them): each a module file beside this one, found by its
+name as a metric's reader is.  Without them it is ``reference.py`` and
+``flops.py``.
+
+A later change adds a cell, a mix or a metric, and a configuration of
+another architecture with its own reference and counts, by adding files
+and entries, and edits no file here.
 """
 
 from __future__ import annotations
 
 import importlib.util
 import json
+import re
+import sys
 from pathlib import Path
+from types import ModuleType
 
-__all__ = ["ROOT", "HERE", "load_benchmark", "Cell", "metric_reader"]
+__all__ = ["ROOT", "HERE", "load_benchmark", "Cell", "metric_reader",
+           "module"]
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -42,6 +58,15 @@ class Cell:
             .read_text())
         self.chips = int(self.entry["chips"])
 
+        def named(key: str, default: str) -> ModuleType:
+            if key in self.config:
+                return module(self.config[key], here)
+            return module(default)
+
+        #: the modules that judge and count this configuration
+        self.reference = named("reference", "reference")
+        self.counts = named("counts", "flops")
+
         def mine(m):
             return "workloads" not in m or name in m["workloads"]
 
@@ -51,11 +76,26 @@ class Cell:
 
 def metric_reader(name: str, directory: Path = HERE / "metrics"):
     """The ``read`` function of ``metrics/<name>.py``."""
-    path = Path(directory) / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
-    if spec is None or not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return module(name, directory).read
+
+
+def module(name: str, directory: Path = HERE) -> ModuleType:
+    """The module ``<directory>/<name>.py``, loaded once a process.  One
+    beside this file is imported as a module of this package, so that it
+    may import from its neighbours (``from .reference import ...``)."""
+    path = Path(directory).resolve() / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no module {name!r} at {path}")
+    if path.parent == HERE and name.isidentifier():
+        return importlib.import_module(f"{__package__}.{name}")
+    key = "chipbench_" + re.sub(r"\W", "_", str(path.with_suffix("")))
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[key]
+            raise
+    return sys.modules[key]

@@ -74,7 +74,8 @@ def main() -> None:
                        clock=time.perf_counter, sleep=time.sleep,
                        marks=[(args.seconds, on_close)])
         rec = Record(run=run, model=cell.config["model"], setup_s=0.0,
-                     device_kind=devs[0].device_kind, seconds=args.seconds)
+                     device_kind=devs[0].device_kind, seconds=args.seconds,
+                     counts=cell.counts)
         out = {"rate": rate, "requests": n_win, **backlog,
                "drain_end_s": run.end,
                "unfinished": sum(1 for s in run.window

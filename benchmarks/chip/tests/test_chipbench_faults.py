@@ -69,10 +69,12 @@ def test_sound_run_is_correct(tiny):
 def test_traced_run_reports_the_per_layer_metrics(tiny):
     r = run(tiny, trace=True)
     assert r["correct"] is True and r["failed"] == 0
-    # the CPU trace has no device plane: the device metric is left out
+    # the CPU trace has no device plane: the device metrics are left out
     assert set(r["metrics"]) == {"gen_lag_ms.p99", "governor_ms_per_tick",
                                  "prefill_ms.mean", "tick_ms.decode_only",
-                                 "tick_ms.admit", "mfu"}
+                                 "tick_ms.admit", "mfu",
+                                 "host_syncs_per_tick"}
+    assert r["metrics"]["host_syncs_per_tick"]["value"] == 1.0
 
 
 def alter_tokens(engine):

@@ -1,8 +1,9 @@
 """The engine's own spans read from a profiler trace (``spans``): a tiny
 ``ServingEngine`` traced on the CPU, idle time given to the innermost
-span on synthetic intervals, the recorded chip trace, and the queue-wait
-reader."""
+span on synthetic intervals, the recorded chip trace, and the readers of
+queue wait, host syncs and the decode step's roofline share."""
 
+import dataclasses
 import sys
 from pathlib import Path
 
@@ -16,7 +17,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.profiler import ProfileData  # noqa: E402
 
-from benchmarks.chip import spans, spec, trace  # noqa: E402
+from benchmarks.chip import flops, peaks, spans, spec, trace  # noqa: E402
 from benchmarks.chip.arrivals import Planned  # noqa: E402
 from benchmarks.chip.driver import Run, Served  # noqa: E402
 from benchmarks.chip.record import Record  # noqa: E402
@@ -111,14 +112,13 @@ def test_syncs_count_the_read_sites_passed(traced):
     eng, _, total, _, et = traced
     # every admission reads its first token once
     assert [s.args["syncs"] for s in et.named("engine.admit")] == [1, 1, 1]
-    # a tick reads its tokens, then a position for each live slot that
-    # its budget did not finish: both slots run on, one finishes, both
-    # finish
+    # a tick reads its tokens and nothing more: the slot positions live
+    # on the host, so no live slot adds a read
     decode = et.named("engine.decode")
     assert [s.args["live"] for s in decode] == [2, 2, 2]
-    assert [s.args["syncs"] for s in decode] == [3, 2, 1]
+    assert [s.args["syncs"] for s in decode] == [1, 1, 1]
     assert sum(s.args["syncs"] for s in et.spans if "syncs" in s.args) \
-        == total == 9
+        == total == 6
 
 
 def test_programs_are_named_for_their_functions(traced):
@@ -224,3 +224,54 @@ def test_queue_wait_reader():
     rec = Record(run=_run([None, None]), model={}, setup_s=0.0,
                  device_kind="x", seconds=1.0)
     assert read(rec) is None
+
+
+def _record(eng, reqs, et):
+    """The traced requests as a run whose tokens all came in a 1-s
+    stretch."""
+    served = [Served(Planned(0.0, r.prompt, r.max_new_tokens), 0.0, r,
+                     stamps=[0.5] * len(r.output)) for r in reqs]
+    return Record(run=Run(1.0, served, served, [], 1.0, 0.0),
+                  model=dataclasses.asdict(eng.cfg), setup_s=0.0,
+                  device_kind="cpu", seconds=1.0, engine=et)
+
+
+def test_host_syncs_per_tick_reads_the_decode_spans(traced):
+    eng, reqs, _, _, et = traced
+    read = spec.metric_reader("host_syncs_per_tick")
+    assert read(_record(eng, reqs, et)) == 1.0
+    assert read(_record(eng, reqs, None)) is None
+    # the CPU trace has no device: no decode program to read
+    for name in ("decode_device_ms.mean", "decode_roofline"):
+        assert spec.metric_reader(name)(_record(eng, reqs, et)) is None
+
+
+def test_decode_roofline_against_a_hand_count(traced, monkeypatch):
+    eng, reqs, _, _, et = traced
+    monkeypatch.setitem(peaks.PEAKS, "cpu", {"hbm_bytes_per_s": 1e9})
+    # two executions of the decode step on the device, 4 and 8 ms
+    et = dataclasses.replace(et, programs={"jit_decode_step": [4e-3, 8e-3],
+                                           "jit_prefill": [1.0]})
+    rec = _record(eng, reqs, et)
+    assert spec.metric_reader("decode_device_ms.mean")(rec) == \
+        pytest.approx(6.0)
+    # weights, smoke llama3.2-1b in bfloat16: per layer q and o 64·64,
+    # k and v 64·16, the MLP 3·64·256; two layers and the tied head
+    # 64·256 over the vocabulary
+    weights = 2 * (2 * (2 * 64 * 64 + 2 * 64 * 16 + 3 * 64 * 256)
+                   + 64 * 256)
+    assert flops.weight_bytes(rec.model) == weights == 270336
+    # K and V of a position: 2 layers × 2 × 2 heads × 8 × 2 bytes, as
+    # the engine's cache holds them
+    per_key = 2 * 2 * 2 * 8 * 2
+    assert sum(x.nbytes for x in jax.tree.leaves(eng.cache)) == \
+        flops.kv_bytes(rec.model, eng.max_batch * eng.max_len) \
+        == per_key * eng.max_batch * eng.max_len
+    # prompts 5, 20, 3 with 3, 4, 2 tokens: decoded token j sees n + j
+    # positions, over the 3 decode steps
+    keys = (5 + 1) + (5 + 2) + (20 + 1) + (20 + 2) + (20 + 3) + (3 + 1)
+    assert len(et.named("engine.decode")) == 3
+    want = 100 * (weights + per_key * keys / 3) / (6e-3 * 1e9)
+    got = spec.metric_reader("decode_roofline")(rec)
+    assert got == pytest.approx(want, rel=1e-12)
+    assert 0 < got <= 100

@@ -21,7 +21,8 @@ from pathlib import Path
 
 import numpy as np
 
-__all__ = ["SPANS", "OUTSIDE", "read_xplane", "reduce", "newest_xplane"]
+__all__ = ["SPANS", "OUTSIDE", "load", "read_xplane", "reduce",
+           "newest_xplane"]
 
 SPANS = ("governor", "tick.admit", "tick.decode", "driver")
 OUTSIDE = "outside driver spans"
@@ -37,18 +38,23 @@ def newest_xplane(log_dir: Path) -> Path:
     return files[-1]
 
 
-def read_xplane(path: Path) -> tuple[dict, list]:
-    """Device operations ``{plane: [(start_ns, end_ns, name), ...]}`` and
-    host spans ``[(name, start_ns, end_ns), ...]`` of one trace file
-    (``.xplane.pb``, or the same compressed, ``.xplane.pb.gz``)."""
+def load(path: Path):
+    """The parsed trace file at ``path`` (``.xplane.pb``, or the same
+    compressed, ``.xplane.pb.gz``), a ``jax.profiler.ProfileData``."""
     from jax.profiler import ProfileData
 
     path = Path(path)
     if path.suffix == ".gz":
-        pd = ProfileData.from_serialized_xspace(
+        return ProfileData.from_serialized_xspace(
             gzip.decompress(path.read_bytes()))
-    else:
-        pd = ProfileData.from_file(str(path))
+    return ProfileData.from_file(str(path))
+
+
+def read_xplane(src) -> tuple[dict, list]:
+    """Device operations ``{plane: [(start_ns, end_ns, name), ...]}`` and
+    host spans ``[(name, start_ns, end_ns), ...]`` of one trace: a file's
+    path, or the file as ``load`` parsed it."""
+    pd = load(src) if isinstance(src, (str, Path)) else src
     devices: dict[str, list] = {}
     host: list = []
     for plane in pd.planes:

@@ -4,9 +4,10 @@ The tree has the layout the program's ``init_params`` declares (read with
 ``jax.eval_shape``, so nothing is computed by the program), and every leaf
 is drawn here from the seed, in the type it is served in:
 
-* matrices: normal with standard deviation 1/√(fan-in), so activations
-  keep unit scale (the embedding: 1/√d, so the tied head gives logits of
-  about unit scale);
+* matrices, and stacks of them such as experts (E, d, F): normal with
+  standard deviation 1/√(fan-in), the second dimension from the last, so
+  activations keep unit scale (the embedding: 1/√d, so the tied head
+  gives logits of about unit scale);
 * norm scales (stored as offsets from 1): normal × 0.1;
 * q/k/v biases: normal × 0.5, large enough to matter in the comparison.
 """
@@ -35,8 +36,8 @@ def _std(names: list[str], shape: tuple[int, ...], stacked: bool) -> float:
     core = shape[1:] if stacked else shape
     if name == "embed":
         return 1.0 / math.sqrt(core[-1])
-    if len(core) == 2:
-        return 1.0 / math.sqrt(core[0])
+    if len(core) >= 2:
+        return 1.0 / math.sqrt(core[-2])
     if name in ("bq", "bk", "bv"):
         return 0.5
     return 0.1
