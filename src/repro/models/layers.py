@@ -140,16 +140,20 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def decode_gqa_attention(q: jax.Array, k_cache: jax.Array,
-                         v_cache: jax.Array, pos: jax.Array, *,
-                         ring: bool,
+                         v_cache: jax.Array, k_new: jax.Array,
+                         v_new: jax.Array, pos: jax.Array, *,
                          softcap: float | None = None) -> jax.Array:
-    """One-token attention against a cache.
+    """One-token attention against a ring cache and the token's own key.
 
-    q: (B, 1, H, D); caches: (B, Sc, K, D); ``pos`` — the position of the
-    current token, scalar (homogeneous batch) or (B,) vector (continuous
-    batching: every slot at its own depth).  ``ring=True`` means the
-    cache is a ring buffer (slot = position mod Sc).  Keys are stored
-    post-RoPE.
+    q: (B, 1, H, D); caches: (B, Sc, K, D), slot = position mod Sc (a
+    cache longer than every position is plain linear indexing), keys
+    stored post-RoPE; k_new, v_new: (B, 1, K, D), the current token's
+    key and value, which the cache does not hold yet.  ``pos`` — the
+    position of the current token, scalar (homogeneous batch) or (B,)
+    vector (continuous batching: every slot at its own depth).  The
+    cache is only read: its slot ``pos mod Sc`` still holds position
+    ``pos - Sc`` and is masked, and the token's own key joins the
+    softmax as one more score.
     """
     B, _, H, D = q.shape
     Sc, K = k_cache.shape[1], k_cache.shape[2]
@@ -158,18 +162,24 @@ def decode_gqa_attention(q: jax.Array, k_cache: jax.Array,
     with jax.named_scope("pallas:flash_decode"):
         qg = q.reshape(B, 1, K, G, D)
         s = _score_block(qg, k_cache, softcap, scale)  # (B,K,G,1,Sc)
+        s_new = _score_block(qg, k_new, softcap, scale)  # (B,K,G,1,1)
         slots = jnp.arange(Sc)
         posb = pos if getattr(pos, "ndim", 0) else jnp.full((B,), pos)
         posb = posb[:, None]                           # (B, 1)
-        if ring:
-            slot_pos = posb - ((posb - slots[None, :]) % Sc)
-            valid = (slot_pos >= 0) & (slot_pos <= posb)
-        else:
-            valid = slots[None, :] <= posb
+        slot_pos = posb - ((posb - slots[None, :]) % Sc)
+        valid = (slot_pos >= 0) & (slot_pos < posb)
         s = jnp.where(valid[:, None, None, None, :], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
-        o = jnp.einsum("bkgqs,bskd->bqkgd", p, v_cache)
-    return o.reshape(B, 1, H, D)
+        m = jnp.maximum(s.max(axis=-1, keepdims=True), s_new)
+        p = jnp.exp(s - m)
+        p_new = jnp.exp(s_new - m)
+        denom = p.sum(axis=-1, keepdims=True) + p_new
+        o = jnp.einsum("bkgqs,bskd->bqkgd",
+                       (p / denom).astype(v_cache.dtype), v_cache,
+                       preferred_element_type=jnp.float32)
+        o = o + jnp.einsum("bkgqs,bskd->bqkgd",
+                           (p_new / denom).astype(v_new.dtype), v_new,
+                           preferred_element_type=jnp.float32)
+    return o.astype(v_cache.dtype).reshape(B, 1, H, D)
 
 
 # ---------------------------------------------------------------------------

@@ -177,7 +177,9 @@ def param_specs(cfg: ModelConfig, rules: Rules, tp_size: int) -> dict:
 
 def _attn_block(h, p, cfg: ModelConfig, rules, *, local: bool,
                 positions, cache=None, pos=None):
-    """Attention (+MLP/MoE) residual block.  Returns (h, aux, new_cache)."""
+    """Attention (+MLP/MoE) residual block.  Returns (h, aux, new_kv):
+    with a decode ``cache``, new_kv is this token's K/V as the cache
+    stores it, (B, 1, K, D) each; without one, None."""
     B, S, d = h.shape
     H, K, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     x = rmsnorm(h, p["ln1"], cfg.norm_eps)
@@ -192,34 +194,21 @@ def _attn_block(h, p, cfg: ModelConfig, rules, *, local: bool,
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
 
-    window = cfg.window if local else None
     new_cache = None
     if cache is not None:
-        # Ring semantics are universal: a full-length cache (Sc ≥ max_len)
-        # behaves identically to linear indexing because slot = pos % Sc
-        # = pos and future slots mask out as invalid.
-        Sc = cache["k"].shape[1]
-        slot = pos % Sc
+        # Decode: the cache holds the earlier positions and is only read.
+        # This token's K/V joins the softmax as it would read back from
+        # the cache, and goes to the caller, which writes every layer's
+        # after the layer loop (decode_step).
         k_st = _cache_store(k, cache["k"].dtype)
         v_st = _cache_store(v, cache["v"].dtype)
-        if getattr(pos, "ndim", 0):
-            # per-slot positions (continuous batching): vmapped updates
-            upd = jax.vmap(lambda c, u, s_:
-                           lax.dynamic_update_slice(c, u, (s_, 0, 0)))
-            ck = upd(cache["k"], k_st, slot)
-            cv = upd(cache["v"], v_st, slot)
-        else:
-            zero = jnp.zeros((), slot.dtype) if hasattr(slot, "dtype") else 0
-            ck = lax.dynamic_update_slice(cache["k"], k_st,
-                                          (zero, slot, zero, zero))
-            cv = lax.dynamic_update_slice(cache["v"], v_st,
-                                          (zero, slot, zero, zero))
-        o = decode_gqa_attention(q, _cache_load(ck), _cache_load(cv),
-                                 pos, ring=True,
+        o = decode_gqa_attention(q, _cache_load(cache["k"]),
+                                 _cache_load(cache["v"]), _cache_load(k_st),
+                                 _cache_load(v_st), pos,
                                  softcap=cfg.attn_softcap)
-        new_cache = {"k": ck, "v": cv}
+        new_cache = {"k": k_st, "v": v_st}
     else:
-        o = gqa_attention(q, k, v, window=window,
+        o = gqa_attention(q, k, v, window=cfg.window if local else None,
                           softcap=cfg.attn_softcap)
     o = constrain(rules, o.reshape(B, S, H * D), "hidden_tp")
     o = o @ p["wo"]
@@ -497,37 +486,78 @@ def cache_specs(cache_shapes, rules: Rules) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _write_rows(cache: jax.Array, rows: jax.Array,
+                pos: jax.Array) -> jax.Array:
+    """Write each batch slot's new row into a ring cache at ``pos mod Sc``.
+
+    cache: (..., B, Sc, K, D), a leading layer axis where stacked; rows:
+    the same with Sc = 1.  A scalar ``pos`` writes the batch in one
+    ``dynamic_update_slice``; a per-slot vector in one a slot, covering
+    every layer the cache stacks.  Each is in place where the caller
+    donates the cache.  The slots are unrolled: on the TPU a
+    ``fori_loop`` over them fails to compile where the cache's minor
+    dimensions are one whole (8, 128) tile (K=8, D=128), and a scan over
+    them, like an indexed ``.at[]`` write (a scatter), copies a whole
+    cache.
+    """
+    lead, Sc = (0,) * (cache.ndim - 4), cache.shape[-3]
+    if pos.ndim == 0:
+        return lax.dynamic_update_slice(cache, rows,
+                                        lead + (0, pos % Sc, 0, 0))
+    for b in range(pos.shape[0]):
+        row = lax.slice_in_dim(rows, b, b + 1, axis=rows.ndim - 4)
+        cache = lax.dynamic_update_slice(cache, row,
+                                         lead + (b, pos[b] % Sc, 0, 0))
+    return cache
+
+
 def decode_step(params: dict, token: jax.Array, pos: jax.Array,
                 cache: dict, cfg: ModelConfig,
                 rules: Rules | None = None
                 ) -> tuple[jax.Array, dict]:
     """One serving step.  token: (B,) int32; pos: int32 scalar or (B,)
-    vector (continuous batching).  Returns (logits (B, V), new cache)."""
+    vector (continuous batching).  Returns (logits (B, V), new cache).
+
+    The layer loop only reads the attention caches and gives out each
+    layer's new K/V; they are written after it (:func:`_write_rows`), so
+    a caller that donates ``cache`` has it updated in place.  Recurrent
+    states come out of the loop whole."""
     h = _embed(params, token[:, None], cfg, rules)
     positions = pos[None] if pos.ndim == 0 else pos[:, None]
 
     def unit(h, xs):
         unit_params, unit_cache = xs
-        new_caches = []
+        outs = []
         for ppos, kind in enumerate(cfg.pattern):
-            h, _, nc = _apply_layer(h, unit_params[ppos], cfg, rules, kind,
-                                    ppos, positions,
-                                    cache=unit_cache[ppos], pos=pos)
-            new_caches.append(nc)
-        return h, tuple(new_caches)
+            h, _, out = _apply_layer(h, unit_params[ppos], cfg, rules, kind,
+                                     ppos, positions,
+                                     cache=unit_cache[ppos], pos=pos)
+            outs.append(out)
+        return h, tuple(outs)
 
-    h, new_blocks = lax.scan(lambda c, xs: unit(c, xs), h,
-                             (params["blocks"], cache["blocks"]))
+    h, block_outs = lax.scan(unit, h, (params["blocks"], cache["blocks"]))
     kinds = cfg.layer_kinds()
     base = cfg.n_units * len(cfg.pattern)
-    new_rest = []
+    rest_kinds = kinds[base:]
+    rest_outs = []
     for i, p in enumerate(params["rest"]):
-        h, _, nc = _apply_layer(h, p, cfg, rules, kinds[base + i],
-                                i % len(cfg.pattern), positions,
-                                cache=cache["rest"][i], pos=pos)
-        new_rest.append(nc)
+        h, _, out = _apply_layer(h, p, cfg, rules, rest_kinds[i],
+                                 i % len(cfg.pattern), positions,
+                                 cache=cache["rest"][i], pos=pos)
+        rest_outs.append(out)
     logits = _unembed(params, h, cfg, rules)
-    return logits[:, 0], {"blocks": new_blocks, "rest": tuple(new_rest)}
+
+    def write(kind, old, out):
+        # attention layers give out their new K/V, recurrent ones their
+        # whole new state
+        if kind in (LayerKind.ATTN, LayerKind.MOE):
+            return {n: _write_rows(old[n], out[n], pos) for n in "kv"}
+        return out
+
+    return logits[:, 0], {
+        "blocks": tuple(map(write, cfg.pattern, cache["blocks"],
+                            block_outs)),
+        "rest": tuple(map(write, rest_kinds, cache["rest"], rest_outs))}
 
 
 def prefill(params: dict, tokens: jax.Array, cfg: ModelConfig,

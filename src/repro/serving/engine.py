@@ -214,8 +214,11 @@ class ServingEngine:
         # snapshot, so this one can change in place
         self.pos = np.zeros((max_batch,), np.int32)
         self.remaining = np.zeros((max_batch,), np.int64)
+        # the step writes its new K/V into the cache it is given: donated,
+        # the cache is updated in place and never copied
         self._decode = jax.jit(_named(functools.partial(decode_step,
-                                                        cfg=cfg)))
+                                                        cfg=cfg)),
+                               donate_argnums=(3,))
         # Prompt-length bucketing avoids a recompile per length.  Right-
         # padding is safe for attention archs (pad slots sit after `pos`
         # and are causally invisible); recurrent states would absorb the

@@ -1,5 +1,8 @@
 """Model substrate: per-arch smoke + decode/forward parity."""
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +12,7 @@ from repro.configs import ARCH_IDS, SHAPES, cell_runnable, get_config, \
     get_smoke_config
 from repro.models import (decode_step, forward, init_params,
                           lm_loss, prefill)
+from repro.serving import ServingEngine
 
 KEY = jax.random.PRNGKey(0)
 
@@ -93,6 +97,98 @@ def test_decode_matches_forward(arch):
             np.asarray(full_logits[:, t], np.float32),
             rtol=2e-2, atol=2e-2,
         )
+
+
+@pytest.mark.parametrize("arch", ["llama3.2-1b", "gemma2-9b",
+                                  "mixtral-8x22b", "recurrentgemma-2b",
+                                  "rwkv6-7b", "qwen1.5-110b"])
+def test_decode_matches_forward_at_mixed_depths(arch):
+    """Continuous batching: each slot prefilled alone to its own depth,
+    then decoded together with per-slot positions.  Every slot's logits
+    must match the full forward at its own position.  The local layers'
+    ring caches (window 16 in the smoke configs) wrap while decoding."""
+    cfg = get_smoke_config(arch).replace(frontend_len=0,
+                                         capacity_factor=16.0)
+    params = init_params(KEY, cfg)
+    S, depths = 32, (4, 9, 16)
+    toks = jax.random.randint(jax.random.PRNGKey(7), (len(depths), S), 0,
+                              cfg.vocab)
+    full_logits, _ = forward(params, toks, cfg)
+
+    caches = [prefill(params, toks[b:b + 1, :t], cfg, max_len=S)[1]
+              for b, t in enumerate(depths)]
+    cache = {"blocks": jax.tree.map(lambda *c: jnp.concatenate(c, 1),
+                                    *[c["blocks"] for c in caches]),
+             "rest": jax.tree.map(lambda *c: jnp.concatenate(c, 0),
+                                  *[c["rest"] for c in caches])}
+    step = jax.jit(functools.partial(decode_step, cfg=cfg))
+    rows = np.arange(len(depths))
+    for i in range(S - max(depths)):
+        pos = np.asarray(depths) + i
+        step_logits, cache = step(params, toks[rows, pos],
+                                  jnp.asarray(pos, jnp.int32), cache)
+        np.testing.assert_allclose(
+            np.asarray(step_logits, np.float32),
+            np.asarray(full_logits[rows, pos], np.float32),
+            rtol=2e-2, atol=2e-2, err_msg=f"positions {pos}")
+
+
+def _fused_roots(hlo: str) -> dict:
+    """Each computation of an HLO module's text, by name, with the opcode
+    of its ROOT instruction."""
+    roots = {}
+    for comp in re.split(r"\n(?=\S)", hlo):
+        head = re.match(r"(?:ENTRY )?%(\S+) ", comp)
+        root = re.search(r"ROOT %\S+ = \S+ ([\w-]+)\(", comp)
+        if head and root:
+            roots[head.group(1)] = root.group(1)
+    return roots
+
+
+@pytest.mark.parametrize("positions", ["vector", "scalar"])
+def test_decode_step_writes_its_cache_in_place(positions):
+    """The engine's decode program takes its cache donated and writes each
+    slot's new K/V into it: the whole cache is aliased input to output,
+    temp memory stays under two layers' K+V (the CPU backend gives each
+    attention dot its layer's K and V as a buffer of their own, one layer
+    at a time), and the only instructions outside fused computations
+    with the stacked cache's shape are the cache itself and in-place
+    writes (fusions whose root is a dynamic-update-slice); no copy or
+    broadcast of it.  float32 throughout: with bf16 the CPU backend adds
+    conversions of its own."""
+    cfg = get_smoke_config("llama3.2-1b").replace(
+        n_layers=4, d_model=128, n_heads=4, kv_heads=2, head_dim=64,
+        param_dtype="float32", cache_dtype="float32")
+    B, max_len = 16, 256
+    engine = ServingEngine(cfg, init_params(KEY, cfg), max_batch=B,
+                           max_len=max_len)
+    pos = engine.pos if positions == "vector" else np.int32(7)
+    compiled = engine._decode.lower(engine.params, engine.tokens, pos,
+                                    engine.cache).compile()
+
+    stacked = engine.cache["blocks"][0]["k"]
+    layer_kv = 2 * stacked[0].nbytes
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == sum(
+        x.nbytes for x in jax.tree.leaves(engine.cache))
+    assert mem.temp_size_in_bytes < 2 * layer_kv, mem
+
+    hlo = compiled.as_text()
+    roots = _fused_roots(hlo)
+    shape = "f32[%s]" % ",".join(map(str, stacked.shape))
+    found = []
+    for comp in re.split(r"\n(?=\S)", hlo):
+        if comp.startswith("%fused") or comp.startswith("HloModule"):
+            continue
+        for op, callee in re.findall(
+                r"= " + re.escape(shape) + r"\{[^}]*\} ([\w-]+)\("
+                r"(?:.*calls=%([\w.-]+))?", comp):
+            found.append(op)
+            if op == "fusion":
+                assert roots[callee] == "dynamic-update-slice", callee
+            else:
+                assert op in ("parameter", "get-tuple-element", "bitcast"), op
+    assert "fusion" in found     # the writes are there, and in place
 
 
 def test_vector_pos_decode_matches_scalar():

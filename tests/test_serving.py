@@ -251,3 +251,23 @@ def test_host_positions_follow_prompt_and_ticks():
     assert sum(r is not None for r in engine.active) == 3
     assert engine.pos.dtype == np.int32 and engine.pos.shape == (3,)
     assert engine._decode._cache_size() == 1
+
+
+@pytest.mark.slow
+def test_admission_between_donated_decode_ticks_matches_reference():
+    """The decode step takes the cache donated and writes into it.  A
+    request admitted after two such ticks is written into the cache the
+    last tick gave back, beside a slot still decoding, and both get the
+    greedy reference's tokens."""
+    engine = ServingEngine(CFG, PARAMS, max_batch=2, max_len=64)
+    first = engine.submit(Request(prompt=[5, 9, 2, 7, 3], max_new_tokens=7))
+    engine.tick()
+    engine.tick()
+    leaves = jax.tree.leaves(engine.cache)
+    engine.tick()
+    assert all(x.is_deleted() for x in leaves)     # donated, not copied
+    late = engine.submit(Request(prompt=[8, 1, 6], max_new_tokens=5))
+    engine.run_until_drained()
+    assert first.done and late.done
+    assert first.output == _greedy_reference([5, 9, 2, 7, 3], 7)
+    assert late.output == _greedy_reference([8, 1, 6], 5)

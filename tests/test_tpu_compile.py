@@ -87,6 +87,25 @@ def test_decode_step_compiles_and_fits(one_chip, llama):
     _fits(compiled)
 
 
+def test_decode_step_writes_in_place_with_whole_tile_cache(one_chip):
+    """Jitted as the engine jits it, the cache donated, the decode step
+    compiles where the cache's minor dimensions are one whole (8, 128)
+    tile (deepseek-coder-33b: 8 KV heads of 128), and its cache is
+    aliased input to output.  A ``fori_loop`` over the slots' writes
+    failed the TPU compiler's checks at this shape."""
+    cfg = get_config("deepseek-coder-33b").replace(n_layers=2)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = _on(one_chip, jax.eval_shape(lambda: init_cache(cfg, 16, 4096)))
+    vec = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, t, pos, c: decode_step(p, t, pos, c, cfg),
+        donate_argnums=(3,)).lower(params, vec, vec, cache).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    _fits(compiled)
+
+
 def test_prefill_compiles_and_fits(one_chip, llama):
     cfg, params = llama
     prompt = jax.ShapeDtypeStruct((1, PROMPT_BUCKET), jnp.int32,
